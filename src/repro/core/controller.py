@@ -187,19 +187,13 @@ def _pad_rows(rows: List[np.ndarray]) -> np.ndarray:
 
 def _score_frontier(rows: List[np.ndarray], support: np.ndarray,
                     freqs: np.ndarray, *, page_size: int) -> np.ndarray:
-    """One batched waste evaluation of the candidate frontier.
-
-    Prefers the Pallas kernel (compiled on TPU, interpret elsewhere);
-    falls back to the vmapped jnp oracle if the kernel stack is
-    unavailable (e.g. a CPU wheel without pallas support).
+    """One batched waste evaluation of the candidate frontier through
+    the Pallas kernel (compiled on TPU, interpret elsewhere). A kernel
+    that fails raises: there is no fallback that would hide the device.
     """
+    from repro.kernels.ops import waste_eval
     batch = _pad_rows(rows)
-    try:
-        from repro.kernels.ops import waste_eval
-        scores = waste_eval(batch, support, freqs, page_size=page_size)
-    except Exception:  # pragma: no cover - kernel stack unavailable
-        from repro.core.waste import waste_batch_jax
-        scores = waste_batch_jax(batch, support, freqs, page_size=page_size)
+    scores = waste_eval(batch, support, freqs, page_size=page_size)
     with deliberate_sync("controller.frontier-scores"):
         return np.asarray(scores, dtype=np.float64)
 
@@ -237,15 +231,11 @@ def score_requests(reqs: List["ScoreRequest"]) -> List[np.ndarray]:
     chunks = np.concatenate(rows_out, axis=0)
     supports = np.concatenate(sup_out, axis=0)
     freqs = np.concatenate(frq_out, axis=0)
-    try:
-        from repro.kernels.ops import waste_eval_fleet
-        with deliberate_sync("controller.fleet-frontier-scores"):
-            scores = np.asarray(waste_eval_fleet(chunks, supports, freqs,
-                                                 page_size=page_size),
-                                dtype=np.float64)
-    except Exception:  # pragma: no cover - kernel stack unavailable
-        return [_score_frontier(r.rows, r.support, r.freqs,
-                                page_size=page_size) for r in reqs]
+    from repro.kernels.ops import waste_eval_fleet
+    with deliberate_sync("controller.fleet-frontier-scores"):
+        scores = np.asarray(waste_eval_fleet(chunks, supports, freqs,
+                                             page_size=page_size),
+                            dtype=np.float64)
     out, at = [], 0
     for n in splits:
         out.append(scores[at:at + n])

@@ -269,7 +269,6 @@ class DeviceSizeSketch:
         self.bucket_width = bucket_width
         self._decay = 0.5 ** (1.0 / half_life) if half_life else 1.0
         self._interpret = interpret
-        self._use_ref = False       # latched once the Pallas path fails
         # window=True turns observe_many into an accumulator: batches
         # buffer on host (raw, untouched) and fold into the sketch in
         # ONE fused dispatch at flush_window() — or transparently, the
@@ -440,8 +439,7 @@ class DeviceSizeSketch:
         with_ref = reference is not None
         ref = reference if with_ref else np.float32(0.0)
         use_kernel = (self._window_kernel if self._window_kernel is not None
-                      else (not self._use_ref
-                            and jax.default_backend() == "tpu"))
+                      else jax.default_backend() == "tpu")
         interpret = False
         if use_kernel:
             from repro.kernels.ops import _default_interpret
@@ -454,27 +452,10 @@ class DeviceSizeSketch:
         # there to avoid per-launch warnings.
         donate = jax.default_backend() != "cpu" and not self._escaped
         decay = np.float32(self._decay)
-        try:
-            fn = _window_flush_fn(metric, use_kernel, interpret,
-                                  self.bucket_width, with_ref, donate)
-            new, drift = fn(self._weights, sizes2d, weights2d, lengths,
-                            decay, decay_totals, ref)
-        except Exception as e:  # pragma: no cover - pallas unavailable
-            if not use_kernel:
-                raise
-            # Latched: don't re-pay a doomed trace per window — but say
-            # so once, or a production run would silently measure the
-            # fallback while reporting itself as the kernel path.
-            import warnings
-            warnings.warn(
-                "DeviceSizeSketch: Pallas sketch_window launch failed "
-                f"({e!r}); latching the jnp fallback for this sketch",
-                RuntimeWarning)
-            self._use_ref = True
-            fn = _window_flush_fn(metric, False, False, self.bucket_width,
-                                  with_ref, donate)
-            new, drift = fn(self._weights, sizes2d, weights2d, lengths,
-                            decay, decay_totals, ref)
+        fn = _window_flush_fn(metric, use_kernel, interpret,
+                              self.bucket_width, with_ref, donate)
+        new, drift = fn(self._weights, sizes2d, weights2d, lengths,
+                        decay, decay_totals, ref)
         self._weights = new
         self._escaped = False
         self.n_dispatches += 1

@@ -21,8 +21,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.treeutil import simple_keystr
-
 # (regex on 'path/leafname', spec builder given leaf ndim)
 # Specs are written for the UNSTACKED leaf; stacked layer dims (leading
 # scan axes) are padded with None automatically by _pad_spec.
@@ -96,7 +94,7 @@ def param_spec(params: Any, mesh: Mesh, *, tp_attention: bool = True
     """
 
     def leaf_spec(path, leaf):
-        name = simple_keystr(path, separator="/")
+        name = jax.tree_util.keystr(path, simple=True, separator="/")
         if not tp_attention and re.search(
                 r"(attn|xattn)/(wq|wk|wv|wo)$", name):
             return P()
@@ -172,7 +170,7 @@ def cache_spec(cache: Any, mesh: Mesh, *, seq_parallel: bool = False,
 
     def leaf_spec(path, leaf):
         parts = [None] * leaf.ndim
-        name = simple_keystr(path, separator="/")
+        name = jax.tree_util.keystr(path, simple=True, separator="/")
         # find batch dim: first dim from the left that divides by n_data
         # skipping stacked layer dims (conventionally small and leading).
         # KV leaves: (L..., B, S, H, D); state leaves: (L..., B, ...)

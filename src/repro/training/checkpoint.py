@@ -28,14 +28,13 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
-from repro.treeutil import simple_keystr
-
 _MANIFEST = "manifest.json"
 
 
 def _leaf_paths(tree: Any) -> Dict[str, Any]:
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    return {simple_keystr(p, separator="."): l for p, l in flat}
+    return {jax.tree_util.keystr(p, simple=True, separator="."): l
+            for p, l in flat}
 
 
 class CheckpointManager:

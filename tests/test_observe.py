@@ -301,6 +301,18 @@ except ImportError:                              # pragma: no cover
 
 
 if HAVE_HYPOTHESIS:
+    class _PruneRecordingHistogram(DecayedSizeHistogram):
+        """Host sketch that remembers every size a prune dropped."""
+
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            self.pruned = set()
+
+        def _prune(self):
+            before = set(self._w)
+            super()._prune()
+            self.pruned |= before - set(self._w)
+
     @hypothesis.given(
         seed=st.integers(0, 2**31 - 1),
         half_life=st.one_of(st.none(), st.floats(5.0, 5000.0)),
@@ -311,12 +323,14 @@ if HAVE_HYPOTHESIS:
     def test_device_sketch_tracks_host_property(seed, half_life, max_bins,
                                                 n):
         """For random streams, decays, and prune pressure: every bin the
-        host sketch kept agrees with the device bucket of the same size,
-        and the device total never undershoots the host's (prunes only
-        ever drop host mass — the device sketch has no prune)."""
+        host sketch kept is at most the device bucket of the same size,
+        and equal to it when the host never pruned that size (a pruned
+        size seen again restarts from its new weight, while the device
+        sketch, which has no prune, keeps its history). The device total
+        never undershoots the host's."""
         rng = np.random.default_rng(seed)
         sizes = rng.integers(1, 512, n)
-        h = DecayedSizeHistogram(half_life=half_life, max_bins=max_bins)
+        h = _PruneRecordingHistogram(half_life=half_life, max_bins=max_bins)
         d = DeviceSizeSketch(half_life=half_life, num_buckets=512)
         for i in range(0, n, 97):
             h.observe_many(sizes[i:i + 97])
@@ -325,7 +339,10 @@ if HAVE_HYPOTHESIS:
         dense = np.zeros(513)
         dense[np.asarray(d.snapshot_weights()[0])] = d.snapshot_weights()[1]
         for s, w in zip(host_s.tolist(), host_w.tolist()):
-            assert dense[s] == pytest.approx(w, rel=1e-3, abs=1e-5)
+            if s in h.pruned:
+                assert w <= dense[s] * (1 + 1e-3) + 1e-5
+            else:
+                assert dense[s] == pytest.approx(w, rel=1e-3, abs=1e-5)
         assert (np.asarray(d.weights_device).sum()
                 >= h.effective_count * (1 - 1e-4))
 

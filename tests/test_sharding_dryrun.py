@@ -34,14 +34,13 @@ def test_param_spec_rules():
     import jax, json
     from repro.models import get_model
     from repro.sharding import param_spec
-    from repro.treeutil import simple_keystr
     from repro.launch.mesh import make_debug_mesh
     cfg, model = get_model("mixtral-8x7b", reduced=True)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     mesh = make_debug_mesh(4, 2)
     spec = param_spec(params, mesh)
     flat = jax.tree_util.tree_flatten_with_path(spec)[0]
-    specs = {simple_keystr(p, separator='/'): str(s)
+    specs = {jax.tree_util.keystr(p, simple=True, separator='/'): str(s)
              for p, s in flat}
     print(json.dumps(specs))
     """)
@@ -63,14 +62,13 @@ def test_zero_spec_adds_data_axis():
     import jax, json
     from repro.models import get_model
     from repro.sharding import zero_spec
-    from repro.treeutil import simple_keystr
     from repro.launch.mesh import make_debug_mesh
     cfg, model = get_model("deepseek-7b", reduced=True)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     mesh = make_debug_mesh(4, 2)
     spec = zero_spec(params, mesh)
     flat = jax.tree_util.tree_flatten_with_path(spec)[0]
-    specs = {simple_keystr(p, separator='/'): str(s)
+    specs = {jax.tree_util.keystr(p, simple=True, separator='/'): str(s)
              for p, s in flat}
     print(json.dumps(specs))
     """)
