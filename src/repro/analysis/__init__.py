@@ -17,7 +17,9 @@ inputs. This package checks them at lint time, on every line:
 * :mod:`repro.analysis.guards` — the *runtime* half: a transfer-guard
   sanitizer (:func:`guards.no_implicit_transfers`) and the
   :func:`guards.deliberate_sync` escape hatch the static rules
-  recognise.
+  recognise, which records each readback as a ``sync:<label>`` span.
+* :mod:`repro.analysis.spans` — the always-on span recorder: a fixed
+  ring of spans at layer boundaries and per-name totals.
 
 Everything except ``guards`` is stdlib-only so the lint CI job needs no
 jax install; ``guards`` imports jax lazily and only when armed.

@@ -21,20 +21,23 @@ loudly (again: emitted on TPU; CPU jax does not warn).
 
 :func:`deliberate_sync` is the escape hatch *both* halves recognise:
 statically, HS001 skips sinks inside ``with deliberate_sync(...):``;
-dynamically it suspends the software patches, enters the native
-``"allow"`` scope, and logs the label to :data:`SYNC_LOG`. When no
-guard is armed it is a true no-op that never imports jax — host-only
-modules can use it freely.
+dynamically it suspends the software patches and enters the native
+``"allow"`` scope. Armed or not, it records a span named
+``sync:<label>`` (:mod:`repro.analysis.spans`), so every labelled
+readback is timed where the host waits on the device. When no guard is
+armed it never imports jax — host-only modules can use it freely.
 """
 from __future__ import annotations
 
 import contextlib
 import threading
 import warnings
-from typing import Iterator, List, Optional
+from typing import Iterator, Optional
+
+from repro.analysis.spans import span
 
 __all__ = ["GuardViolation", "no_implicit_transfers", "deliberate_sync",
-           "SYNC_LOG", "guards_armed"]
+           "guards_armed"]
 
 
 class GuardViolation(RuntimeError):
@@ -47,7 +50,6 @@ _LOCK = threading.Lock()
 _DEPTH = 0          # no_implicit_transfers nesting
 _SYNC_DEPTH = 0     # deliberate_sync nesting (while armed)
 _SAVED: dict = {}   # patched attr -> original
-SYNC_LOG: List[Optional[str]] = []   # labels of deliberate syncs seen
 
 
 def guards_armed() -> bool:
@@ -115,22 +117,23 @@ def no_implicit_transfers(*, donation_errors: bool = True
 
 @contextlib.contextmanager
 def deliberate_sync(label: Optional[str] = None) -> Iterator[None]:
-    """Mark a deliberate device→host readback (cadence boundaries).
+    """Mark a deliberate device→host readback (cadence boundaries) and
+    record it as a ``sync:<label>`` span.
 
-    No-op when no guard is armed — never imports jax, so host-only
-    sketches can run through it with zero overhead.
+    Unarmed it only records the span — it never imports jax, so
+    host-only sketches can run through it.
     """
     global _SYNC_DEPTH
-    if _DEPTH == 0:
-        yield
-        return
-    import jax
-    with _LOCK:
-        _SYNC_DEPTH += 1
-        SYNC_LOG.append(label)
-    try:
-        with jax.transfer_guard_device_to_host("allow"):
+    with span(f"sync:{label}"):
+        if _DEPTH == 0:
             yield
-    finally:
+            return
+        import jax
         with _LOCK:
-            _SYNC_DEPTH -= 1
+            _SYNC_DEPTH += 1
+        try:
+            with jax.transfer_guard_device_to_host("allow"):
+                yield
+        finally:
+            with _LOCK:
+                _SYNC_DEPTH -= 1

@@ -61,6 +61,7 @@ import numpy as np
 
 from repro.analysis.guards import deliberate_sync
 from repro.analysis.registry import hot_path
+from repro.analysis.spans import span
 from repro.core.controller import (ControllerConfig, ScoreRequest,
                                    SlabController, _score_frontier,
                                    score_requests)
@@ -556,14 +557,15 @@ class TenantArbiter:
         the due tenants with one vectorized mask over the stacked
         cadence mirror (kept in sync by :meth:`set`/:meth:`observe`)
         and batches their device drift gates into one launch."""
-        self.n_ops += int(n)
-        self._since_arbitrate += int(n)
-        if self.fleet is None:
-            self._drain_checks(self.tenants.values())
-        else:
-            self._drain_checks_fleet()
-        if self._since_arbitrate >= self.arbitrate_every:
-            self.arbitrate()
+        with span("arbiter.tick"):
+            self.n_ops += int(n)
+            self._since_arbitrate += int(n)
+            if self.fleet is None:
+                self._drain_checks(self.tenants.values())
+            else:
+                self._drain_checks_fleet()
+            if self._since_arbitrate >= self.arbitrate_every:
+                self.arbitrate()
 
     @hot_path(counters=("n_admission_checks", "n_admission_denials"))
     def admission(self, name: str, units: int = 1) -> bool:
@@ -665,24 +667,28 @@ class TenantArbiter:
             return
         self.n_score_launches += 1
         self.n_frontiers_scored += len(pending)
-        if len(pending) == 1:
-            t, req = pending[0]
-            scores = [_score_frontier(req.rows, req.support, req.freqs,
-                                      page_size=req.page_size)]
-        else:
-            # group by page_size (a static kernel parameter); in
-            # practice one group — one launch per tick
-            by_ps: Dict[int, List] = {}
-            for t, req in pending:
-                by_ps.setdefault(req.page_size, []).append(req)
-            scored = {}
-            for reqs in by_ps.values():
-                for req, s in zip(reqs, score_requests(reqs)):
-                    scored[id(req)] = s
-            self.n_score_launches += len(by_ps) - 1
-            scores = [scored[id(req)] for _, req in pending]
+        # score_requests and _score_frontier are looked up in the module
+        # at call time: a caller may put its own in their place
+        with span("arbiter.score"):
+            if len(pending) == 1:
+                t, req = pending[0]
+                scores = [_score_frontier(req.rows, req.support, req.freqs,
+                                          page_size=req.page_size)]
+            else:
+                # group by page_size (a static kernel parameter); in
+                # practice one group — one launch per tick
+                by_ps: Dict[int, List] = {}
+                for t, req in pending:
+                    by_ps.setdefault(req.page_size, []).append(req)
+                scored = {}
+                for reqs in by_ps.values():
+                    for req, s in zip(reqs, score_requests(reqs)):
+                        scored[id(req)] = s
+                self.n_score_launches += len(by_ps) - 1
+                scores = [scored[id(req)] for _, req in pending]
         for (t, req), s in zip(pending, scores):
-            self._apply_refit(t, t.controller.finish_check(req, s))
+            with span("arbiter.apply"):
+                self._apply_refit(t, t.controller.finish_check(req, s))
 
     def _drain_checks_fleet(self) -> None:
         """Fleet due-scan: one vectorized mask over the stacked cadence
@@ -709,33 +715,34 @@ class TenantArbiter:
         and bit-identical to legacy, matching the score-launch idiom.
         Groups by (metric, grid) — one launch per group; fleets share
         a controller_config, so in practice one group, one launch."""
-        ready = [t for t in due
-                 if t.controller._device
-                 and t.controller.reference is not None
-                 and t.controller.sketch.n_observed > 0]
-        if len(ready) < 2:
-            return None
-        groups: Dict[Tuple[str, int], List[_Tenant]] = {}
-        for t in ready:
-            key = (t.controller.config.drift_metric,
-                   int(t.controller.sketch.num_buckets))
-            groups.setdefault(key, []).append(t)
-        from repro.kernels.fleet_gate import drift_gate_fleet
-        import jax.numpy as jnp
-        out: Dict[int, float] = {}
-        for (metric, _), ts in groups.items():
-            for t in ts:
-                t.controller.sketch.flush_window()
-            refs = jnp.stack([t.controller.reference for t in ts])
-            live = jnp.stack([t.controller.sketch.weights_device
-                              for t in ts])
-            with deliberate_sync("arbiter.fleet-drift-gate"):
-                vals = np.asarray(drift_gate_fleet(refs, live,
-                                                   metric=metric))
-            self.n_gate_launches += 1
-            for t, v in zip(ts, vals):
-                out[id(t)] = float(v)
-        return out
+        with span("arbiter.gate"):
+            ready = [t for t in due
+                     if t.controller._device
+                     and t.controller.reference is not None
+                     and t.controller.sketch.n_observed > 0]
+            if len(ready) < 2:
+                return None
+            groups: Dict[Tuple[str, int], List[_Tenant]] = {}
+            for t in ready:
+                key = (t.controller.config.drift_metric,
+                       int(t.controller.sketch.num_buckets))
+                groups.setdefault(key, []).append(t)
+            from repro.kernels.fleet_gate import drift_gate_fleet
+            import jax.numpy as jnp
+            out: Dict[int, float] = {}
+            for (metric, _), ts in groups.items():
+                for t in ts:
+                    t.controller.sketch.flush_window()
+                refs = jnp.stack([t.controller.reference for t in ts])
+                live = jnp.stack([t.controller.sketch.weights_device
+                                  for t in ts])
+                with deliberate_sync("arbiter.fleet-drift-gate"):
+                    vals = np.asarray(drift_gate_fleet(refs, live,
+                                                       metric=metric))
+                self.n_gate_launches += 1
+                for t, v in zip(ts, vals):
+                    out[id(t)] = float(v)
+            return out
 
     # -- arbitration ---------------------------------------------------------
     def _refresh_pressure(self) -> None:
@@ -796,6 +803,10 @@ class TenantArbiter:
     @hot_path(counters=("n_transfers", "n_bounced"))
     def arbitrate(self) -> List[TransferDecision]:
         """One arbitration round; returns this round's decisions."""
+        with span("arbiter.arbitrate"):
+            return self._arbitrate()
+
+    def _arbitrate(self) -> List[TransferDecision]:
         if self.fleet is not None:
             return self._arbitrate_fleet()
         self._since_arbitrate = 0

@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.analysis.guards import deliberate_sync
 from repro.analysis.registry import hot_path
+from repro.analysis.spans import span
 from repro.core.distribution import PAGE_SIZE
 from repro.core.observe import (DecayedSizeHistogram, DeviceSizeSketch,
                                 histogram_distance,
@@ -596,36 +597,40 @@ class SlabController:
         """Build the candidate frontier once every gate up to scoring
         has passed: returns a :class:`ScoreRequest`, or a final
         :class:`RefitDecision` when there is nothing to score."""
-        cfg = self.config
-        predictive = forecast is not None
-        if predictive:
-            support, freqs, new_reference = self._forecast_mixture(forecast)
-            if support.size == 0:
-                return self._decide(False, "empty-forecast", drift,
-                                    predictive=True,
-                                    forecast_drift=forecast_drift)
-        else:
-            support, freqs = self.sketch.snapshot()
-            new_reference = None
-            if support.size == 0:
-                return self._decide(False, "empty-sketch", drift)
-        k = cfg.k or len(self.chunks)
-        fitted = self.policy.fit(support, freqs, k, method=cfg.method,
-                                 baseline=self.chunks)
-        candidates = [self.chunks,
-                      _quantize_up(fitted.chunk_sizes, cfg.align)]
-        from repro.core.slab_policy import covering_default_classes
-        defaults = _quantize_up(
-            covering_default_classes(support, k=k, page_size=cfg.page_size),
-            cfg.align)
-        if defaults.size:
-            candidates.append(defaults)
-        return ScoreRequest(rows=candidates, support=support, freqs=freqs,
-                            page_size=cfg.page_size, drift=drift,
-                            cost_bytes_fn=cost_bytes_fn,
-                            predictive=predictive,
-                            forecast_drift=forecast_drift,
-                            new_reference=new_reference)
+        with span("controller.frontier"):
+            cfg = self.config
+            predictive = forecast is not None
+            if predictive:
+                support, freqs, new_reference = self._forecast_mixture(
+                    forecast)
+                if support.size == 0:
+                    return self._decide(False, "empty-forecast", drift,
+                                        predictive=True,
+                                        forecast_drift=forecast_drift)
+            else:
+                support, freqs = self.sketch.snapshot()
+                new_reference = None
+                if support.size == 0:
+                    return self._decide(False, "empty-sketch", drift)
+            k = cfg.k or len(self.chunks)
+            with span("controller.fit"):
+                fitted = self.policy.fit(support, freqs, k, method=cfg.method,
+                                         baseline=self.chunks)
+            candidates = [self.chunks,
+                          _quantize_up(fitted.chunk_sizes, cfg.align)]
+            from repro.core.slab_policy import covering_default_classes
+            defaults = _quantize_up(
+                covering_default_classes(support, k=k,
+                                         page_size=cfg.page_size),
+                cfg.align)
+            if defaults.size:
+                candidates.append(defaults)
+            return ScoreRequest(rows=candidates, support=support, freqs=freqs,
+                                page_size=cfg.page_size, drift=drift,
+                                cost_bytes_fn=cost_bytes_fn,
+                                predictive=predictive,
+                                forecast_drift=forecast_drift,
+                                new_reference=new_reference)
 
     @hot_path(counters=("n_refits",))
     def finish_check(self, req: ScoreRequest,

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.registry import hot_path
+from repro.analysis.spans import span
 from repro.kernels.kv_scatter import (kv_append_pallas, kv_append_ref,
                                       kv_chunk_copy_pallas,
                                       kv_chunk_copy_ref)
@@ -432,64 +433,67 @@ class OfflineHarness:
     def _dispatch_prefill(self, plan) -> None:
         if not plan:
             return
-        if self.mode == "batched":
-            starts = np.zeros(self.max_batch, np.int32)
-            plens = np.zeros(self.max_batch, np.int32)
-            rids = np.zeros(self.max_batch, np.int32)
-            for i, (_slot, start, plen, rid) in enumerate(plan):
-                starts[i], plens[i], rids[i] = start, plen, rid
-            # starts/plens/rids are freshly built per call: safe to
-            # hand to jnp.asarray without copying
-            self._k, self._v = self._prefill(
-                self._k, self._v, jnp.asarray(starts), jnp.asarray(plens),
-                jnp.asarray(rids))
-            self.n_prefill_dispatches += 1
-            return
-        for _slot, start, plen, rid in plan:
-            self._k, self._v = self._prefill(
-                self._k, self._v,
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray([plen], jnp.int32),
-                jnp.asarray([rid], jnp.int32))
-            self.n_prefill_dispatches += 1
+        with span("harness.prefill_dispatch"):
+            if self.mode == "batched":
+                starts = np.zeros(self.max_batch, np.int32)
+                plens = np.zeros(self.max_batch, np.int32)
+                rids = np.zeros(self.max_batch, np.int32)
+                for i, (_slot, start, plen, rid) in enumerate(plan):
+                    starts[i], plens[i], rids[i] = start, plen, rid
+                # starts/plens/rids are freshly built per call: safe to
+                # hand to jnp.asarray without copying
+                self._k, self._v = self._prefill(
+                    self._k, self._v, jnp.asarray(starts), jnp.asarray(plens),
+                    jnp.asarray(rids))
+                self.n_prefill_dispatches += 1
+                return
+            for _slot, start, plen, rid in plan:
+                self._k, self._v = self._prefill(
+                    self._k, self._v,
+                    jnp.asarray([start], jnp.int32),
+                    jnp.asarray([plen], jnp.int32),
+                    jnp.asarray([rid], jnp.int32))
+                self.n_prefill_dispatches += 1
 
     def _dispatch_decode(self, plan) -> None:
         if not plan:
             return
-        if self.mode == "batched":
-            mv_src = np.zeros(self.max_batch, np.int32)
-            mv_dst = np.zeros(self.max_batch, np.int32)
-            mv_tok = np.zeros(self.max_batch, np.int32)
-            n_mv = 0
-            for _slot, src, dst, tok in plan:
-                if tok:
-                    mv_src[n_mv], mv_dst[n_mv], mv_tok[n_mv] = src, dst, tok
-                    n_mv += 1
-            # .copy(): jnp.asarray may zero-copy a host array, and the
-            # async-dispatched step can read it AFTER the next tick's
-            # bookkeeping mutates the slot state in place
-            self._k, self._v, tokens = self._decode(
-                self._k, self._v, jnp.asarray(self._starts.copy()),
-                jnp.asarray(self._lens.copy()),
-                jnp.asarray(self._rids.copy()),
-                jnp.asarray(self._act.copy()), jnp.asarray(mv_src),
-                jnp.asarray(mv_dst), jnp.asarray(mv_tok))
-            self.n_decode_dispatches += 1
-            snap = tuple(int(self._rids[s]) if self._act[s] else None
-                         for s in range(self.max_batch))
-            self._token_log.append((snap, tokens))
-            return
-        for slot, src, dst, tok in plan:
-            self._k, self._v, tokens = self._decode(
-                self._k, self._v,
-                jnp.asarray(self._starts[slot:slot + 1].copy()),
-                jnp.asarray(self._lens[slot:slot + 1].copy()),
-                jnp.asarray(self._rids[slot:slot + 1].copy()),
-                jnp.asarray(self._act[slot:slot + 1].copy()),
-                jnp.asarray([src], np.int32), jnp.asarray([dst], np.int32),
-                jnp.asarray([tok], np.int32))
-            self.n_decode_dispatches += 1
-            self._token_log.append(((int(self._rids[slot]),), tokens))
+        with span("harness.decode_dispatch"):
+            if self.mode == "batched":
+                mv_src = np.zeros(self.max_batch, np.int32)
+                mv_dst = np.zeros(self.max_batch, np.int32)
+                mv_tok = np.zeros(self.max_batch, np.int32)
+                n_mv = 0
+                for _slot, src, dst, tok in plan:
+                    if tok:
+                        mv_src[n_mv], mv_dst[n_mv] = src, dst
+                        mv_tok[n_mv] = tok
+                        n_mv += 1
+                # .copy(): jnp.asarray may zero-copy a host array, and the
+                # async-dispatched step can read it AFTER the next tick's
+                # bookkeeping mutates the slot state in place
+                self._k, self._v, tokens = self._decode(
+                    self._k, self._v, jnp.asarray(self._starts.copy()),
+                    jnp.asarray(self._lens.copy()),
+                    jnp.asarray(self._rids.copy()),
+                    jnp.asarray(self._act.copy()), jnp.asarray(mv_src),
+                    jnp.asarray(mv_dst), jnp.asarray(mv_tok))
+                self.n_decode_dispatches += 1
+                snap = tuple(int(self._rids[s]) if self._act[s] else None
+                             for s in range(self.max_batch))
+                self._token_log.append((snap, tokens))
+                return
+            for slot, src, dst, tok in plan:
+                self._k, self._v, tokens = self._decode(
+                    self._k, self._v,
+                    jnp.asarray(self._starts[slot:slot + 1].copy()),
+                    jnp.asarray(self._lens[slot:slot + 1].copy()),
+                    jnp.asarray(self._rids[slot:slot + 1].copy()),
+                    jnp.asarray(self._act[slot:slot + 1].copy()),
+                    jnp.asarray([src], np.int32), jnp.asarray([dst], np.int32),
+                    jnp.asarray([tok], np.int32))
+                self.n_decode_dispatches += 1
+                self._token_log.append(((int(self._rids[slot]),), tokens))
 
     # -- the tick -------------------------------------------------------------
     @hot_path(counters=("n_decode_dispatches", "n_prefill_dispatches"))
@@ -497,13 +501,25 @@ class OfflineHarness:
         """One serving tick: admit → prefill dispatch → decode
         bookkeeping → ONE decode dispatch (batched mode) → frees →
         observe/arbitrate/refit. No device value is synced to host
-        here — tokens stay on device until :meth:`result`."""
-        observed: List[int] = []
-        prefill_plan = self._admit_phase(t, observed)
-        self._dispatch_prefill(prefill_plan)
-        decode_plan, finished = self._decode_phase(observed)
-        self._dispatch_decode(decode_plan)
-        self._release(finished)
+        here — tokens stay on device until :meth:`result`. The
+        dispatches are made through ``self.`` so that a caller may wrap
+        them on the instance."""
+        with span("harness.tick"):
+            observed: List[int] = []
+            with span("harness.admit"):
+                prefill_plan = self._admit_phase(t, observed)
+            self._dispatch_prefill(prefill_plan)
+            with span("harness.decode_phase"):
+                decode_plan, finished = self._decode_phase(observed)
+            self._dispatch_decode(decode_plan)
+            with span("harness.release"):
+                self._release(finished)
+            with span("harness.observe"):
+                self._observe(observed)
+
+    def _observe(self, observed: List[int]) -> None:
+        """The tick's tail: observed lengths into the pool's sketch,
+        the arbiter's cadence, and the pool's refit check."""
         if self.pool.batch_observe and observed:
             self.pool.observe_lengths(np.asarray(observed, dtype=np.int64))
         if self.arbiter is not None:

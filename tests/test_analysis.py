@@ -5,6 +5,7 @@ host sync to the arbiter tick) flip CI red. Plus runtime coverage for
 the transfer-guard sanitizer (repro.analysis.guards)."""
 from __future__ import annotations
 
+import os
 import shutil
 import subprocess
 import sys
@@ -283,22 +284,36 @@ def test_guard_blocks_implicit_scalar_sync():
 
 def test_deliberate_sync_allows_and_logs():
     jnp = pytest.importorskip("jax.numpy")
-    from repro.analysis import guards
+    from repro.analysis import guards, spans
     x = jnp.ones(())
+
+    def n_syncs():
+        return spans.totals().get("sync:test.readback", (0, 0))[0]
+
+    before = n_syncs()
     with guards.no_implicit_transfers():
         with guards.deliberate_sync("test.readback"):
             assert float(x) == 1.0
-        assert "test.readback" in guards.SYNC_LOG
+        assert n_syncs() == before + 1   # the readback is a timed span
         with pytest.raises(guards.GuardViolation):
             float(x)                     # re-armed after the sync block
 
 
 def test_deliberate_sync_is_noop_when_unarmed():
-    from repro.analysis import guards
-    before = len(guards.SYNC_LOG)
-    with guards.deliberate_sync("test.unarmed"):
-        pass
-    assert len(guards.SYNC_LOG) == before
+    # a fresh interpreter: unarmed, the seam records its span, raises
+    # nothing and never imports jax
+    code = (
+        "import sys\n"
+        "from repro.analysis import spans\n"
+        "from repro.analysis.guards import deliberate_sync\n"
+        "with deliberate_sync('test.unarmed'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "assert spans.totals()['sync:test.unarmed'][0] == 1\n")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env={**os.environ, "PYTHONPATH": src})
+    assert out.returncode == 0, out.stderr
 
 
 def test_guard_nesting_reference_counts():
