@@ -58,6 +58,7 @@ def run_rules(project: Project,
 # whose results live in accelerator memory.
 DEVICE_FNS = {
     "histogram_distance_device", "_dense_distance", "drift_gate_fleet",
+    "fold_gate_fleet",
     "waste_eval", "waste_eval_fleet", "waste_eval_pallas",
     "waste_eval_fleet_pallas", "waste_eval_ref", "waste_eval_fleet_ref",
     "sketch_update", "sketch_update_pallas", "sketch_update_ref",

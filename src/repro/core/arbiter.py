@@ -392,9 +392,11 @@ class TenantArbiter:
         # sketches) and every arbitration stage runs batched over the
         # whole fleet; the per-tenant loop above stays available as the
         # bit-exact oracle (fleet=False). n_gate_launches counts the
-        # one-launch-per-tick batched drift gate.
+        # one-launch-per-tick batched drift gate, n_gate_rows_folded
+        # the due tenants whose observe windows it folded.
         self.fleet = None
         self.n_gate_launches = 0
+        self.n_gate_rows_folded = 0
         self._by_row: Dict[int, _Tenant] = {}
         self._sorted_cache: Optional[List[_Tenant]] = None
         if fleet:
@@ -545,7 +547,7 @@ class TenantArbiter:
         return deleted
 
     @hot_path(counters=("n_score_launches", "n_gate_launches",
-                        "n_frontiers_scored"))
+                        "n_gate_rows_folded", "n_frontiers_scored"))
     def tick(self, n: int = 1) -> None:
         """Advance the arbitration cadence by ``n`` operations that did
         NOT route through :meth:`set`/:meth:`get`/:meth:`delete` — the
@@ -706,40 +708,54 @@ class TenantArbiter:
             f.since_check[t.row] = t.controller._since_check
 
     def _batched_gate(self, due) -> Optional[Dict[int, float]]:
-        """One ``drift_gate_fleet`` launch + one vector readback for
-        every due device-sketch tenant with an adopted reference.
+        """One fused launch + one vector readback for every due tenant
+        whose device sketch is a fleet row with an adopted reference.
 
-        Returns ``id(tenant) -> drift`` for the gated tenants (others
-        fall through to their controller's solo gate). A single ready
+        The launch (``fold_gate_fleet``) folds each such tenant's
+        buffered observe window into its row of the stacked fleet
+        sketch and computes the folded rows' drift, so the tenants'
+        own ``begin_check`` finds nothing left to flush. Returns
+        ``id(tenant) -> drift`` for the gated tenants (others fall
+        through to their controller's solo gate). A single ready
         tenant uses the solo fused flush+gate — same one-launch cost,
         and bit-identical to legacy, matching the score-launch idiom.
-        Groups by (metric, grid) — one launch per group; fleets share
-        a controller_config, so in practice one group, one launch."""
+        Groups by (metric, grid, bucket width) — one launch per group;
+        fleets share a controller_config, so in practice one group, one
+        launch."""
+        from repro.core.fleet import FleetSketchView
         with span("arbiter.gate"):
             ready = [t for t in due
-                     if t.controller._device
+                     if isinstance(t.controller.sketch, FleetSketchView)
                      and t.controller.reference is not None
                      and t.controller.sketch.n_observed > 0]
             if len(ready) < 2:
                 return None
-            groups: Dict[Tuple[str, int], List[_Tenant]] = {}
+            groups: Dict[Tuple[str, int, int], List[_Tenant]] = {}
             for t in ready:
+                sk = t.controller.sketch
                 key = (t.controller.config.drift_metric,
-                       int(t.controller.sketch.num_buckets))
+                       int(sk.num_buckets), int(sk.bucket_width))
                 groups.setdefault(key, []).append(t)
-            from repro.kernels.fleet_gate import drift_gate_fleet
-            import jax.numpy as jnp
+            import jax
+            from repro.kernels.fleet_gate import (fold_gate_fleet,
+                                                  pack_fleet_windows)
+            f = self.fleet
+            donate = jax.default_backend() != "cpu"
             out: Dict[int, float] = {}
-            for (metric, _), ts in groups.items():
-                for t in ts:
-                    t.controller.sketch.flush_window()
-                refs = jnp.stack([t.controller.reference for t in ts])
-                live = jnp.stack([t.controller.sketch.weights_device
-                                  for t in ts])
+            for (metric, _, width), ts in groups.items():
+                with span("arbiter.gate.pack"):
+                    operands = pack_fleet_windows(
+                        [t.row for t in ts],
+                        [t.controller.sketch.take_window() for t in ts],
+                        [t.controller.reference for t in ts],
+                        capacity=f.sketch.shape[0])
+                f.sketch, drift = fold_gate_fleet(
+                    f.sketch, *operands, metric=metric,
+                    bucket_width=width, donate=donate)
                 with deliberate_sync("arbiter.fleet-drift-gate"):
-                    vals = np.asarray(drift_gate_fleet(refs, live,
-                                                       metric=metric))
+                    vals = np.asarray(drift)
                 self.n_gate_launches += 1
+                self.n_gate_rows_folded += len(ts)
                 for t, v in zip(ts, vals):
                     out[id(t)] = float(v)
             return out

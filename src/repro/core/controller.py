@@ -439,10 +439,10 @@ class SlabController:
 
         ``precomputed_drift`` is the fleet seam: when the arbiter has
         already computed this controller's drift in a batched gate
-        launch (``repro.kernels.fleet_gate.drift_gate_fleet`` over
+        launch (``repro.kernels.fleet_gate.fold_gate_fleet`` over
         every due tenant at once), passing it here skips the solo
         distance computation — the rest of the pipeline runs
-        unchanged. The caller is responsible for having flushed any
+        unchanged. The caller is responsible for having folded any
         buffered device window before computing the value it passes.
         """
         if self._since_check < self.config.check_every:

@@ -113,9 +113,13 @@ class FleetSketchView(DeviceSizeSketch):
     ``fleet.sketch.at[row].set(...)``, so every inherited method
     (observe_many, flush_window, snapshot, drift fusion, donation)
     operates on the stacked ``[capacity, num_buckets]`` fleet matrix
-    without knowing it. The arbiter's batched drift gate slices the
-    same matrix, so due tenants never need their sketches gathered
-    one by one.
+    without knowing it. Those paths pay a dispatch and an eager write
+    of the row per window. The arbiter's batched drift gate does not:
+    it takes each due tenant's buffered window (:meth:`take_window`)
+    and folds them all into the matrix in the same launch that gates
+    the folded rows (``repro.kernels.fleet_gate.fold_gate_fleet``).
+    Earlier, each due tenant flushed its own window through the
+    inherited path before the batched distance ran.
     """
 
     def __init__(self, fleet: "FleetState", row: int, **kwargs):
@@ -136,6 +140,46 @@ class FleetSketchView(DeviceSizeSketch):
     def _weights(self, value) -> None:
         f = self._fleet
         f.sketch = f.sketch.at[self._row].set(value)
+
+    def take_window(self):
+        """Hand over the buffered observe window and clear the buffer.
+
+        Returns ``(sizes, weights, decay_total)`` — int32 raw sizes in
+        observation order, float64 weights that carry the within-window
+        decay, and the row's decay over the whole window — or None when
+        nothing is buffered. The decay follows the per-batch scan of
+        :meth:`_launch`: item i of an n-item batch carries the float32
+        decay to the power ``n-1-i``, times the float32 totals
+        ``decay ** n`` of the batches after it, so the fold rounds like
+        the solo path. The caller must fold them into this row
+        before the row is read. ``n_observed`` already counts the items.
+        A window holding device-resident batches is folded here, by the
+        inherited one-launch flush, and None is returned.
+        """
+        import jax
+        rows, self._pending = self._pending, []
+        if not rows:
+            return None
+        self._escaped = False       # the row is replaced by the fold
+        if any(isinstance(a, jax.Array) for s, w, _ in rows
+               for a in (s, w)):
+            self._launch(rows)
+            return None
+        sizes = np.concatenate([s for s, _, _ in rows]).astype(np.int32)
+        weights = np.concatenate([
+            np.ones(n) if w is None
+            else np.broadcast_to(np.asarray(w, dtype=np.float64), (n,))
+            for _, w, n in rows])
+        if self._decay == 1.0:
+            return sizes, weights, 1.0
+        ns = [n for _, _, n in rows]
+        totals = np.asarray([self._decay ** n for n in ns],
+                            dtype=np.float32).astype(np.float64)
+        later = np.append(np.cumprod(totals[::-1])[::-1][1:], 1.0)
+        within = np.concatenate([np.arange(n - 1, -1, -1) for n in ns])
+        weights = (weights * float(np.float32(self._decay)) ** within
+                   * np.repeat(later, ns))
+        return sizes, weights, float(np.prod(totals))
 
 
 class FleetState:

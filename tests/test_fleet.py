@@ -10,8 +10,9 @@ observe/tick serving mode with device sketches (where the batched gate
 replaces per-tenant launches — decisions must still agree), plus unit
 tests for the stacked-state plumbing itself: row alloc/free/reuse
 zeroing, capacity growth, ``FleetSketchView`` aliasing, the batched
-drift gate vs the scalar distance, and ``acf_period_batch`` vs the
-scalar forecaster.
+drift gate vs the scalar distance, the gate's fused fold of every due
+tenant's observe window vs solo sketches, and ``acf_period_batch`` vs
+the scalar forecaster.
 
 When ``hypothesis`` is installed, a fuzz layer searches random tenant
 counts / seeds / pool shapes for parity violations; the deterministic
@@ -377,6 +378,133 @@ def test_fleet_demand_growth_matches_scalar():
     for i, n in enumerate(("a", "b", "c")):
         g, c = fc.demand_growth(n, 1)
         assert growth[i] == g and conf[i] == c
+
+
+# ---------------------------------------------------------------------------
+# the gate's fused window fold
+# ---------------------------------------------------------------------------
+
+FOLD_EVERY = 64
+
+
+def _fold_fleet(n_ready, half_life):
+    """A device-sketch fleet with ``n_ready`` tenants holding adopted
+    references, two tenants with windows too short to come due, and one
+    freed row. No check can refit (threshold above any distance)."""
+    pool = PagePool(4 * (n_ready + 3), page_size=PAGE)
+    cfg = ControllerConfig(page_size=PAGE, check_every=FOLD_EVERY,
+                           device=True, half_life=half_life,
+                           drift_threshold=2.0)
+    arb = TenantArbiter(pool, controller_config=cfg,
+                        arbitrate_every=10**9, fleet=True,
+                        fleet_capacity=4)
+    names = [f"t{i}" for i in range(n_ready + 3)]
+    for name in names:
+        arb.register(name, SlabAllocator(CLASSES, page_size=PAGE,
+                                         page_pool=pool, tenant=name))
+    arb.remove(names[-1])
+    ready, idle = names[:n_ready], names[n_ready:-1]
+    rng = np.random.default_rng(n_ready)
+    first = {n: rng.integers(1, 6000, size=FOLD_EVERY) for n in ready}
+    for name in ready:          # first check adopts the reference
+        arb.observe(name, first[name])
+    arb.tick(1)
+    assert all(arb.tenants[n].controller.reference is not None
+               for n in ready)
+    return arb, ready, idle, rng, first
+
+
+def _fold_batches(rng, i):
+    """Ragged batches of one ready tenant's window: sizes past the grid
+    (clamped into the top bucket), size 0 and a negative size (dropped)
+    included; odd tenants carry integer weights."""
+    batches = [rng.integers(-1, 3 * PAGE, size=int(n))
+               for n in rng.integers(1, 50, size=1 + i % 4)]
+    batches[0][0] = -5
+    batches.append(rng.integers(1, 4000, size=FOLD_EVERY))
+    weights = [None if i % 2 == 0 else np.full(len(b), 2.0)
+               for b in batches]
+    return batches, weights
+
+
+@pytest.mark.parametrize("half_life", [float("inf"), None],
+                         ids=["undecayed", "decayed"])
+@pytest.mark.parametrize("n_ready", [2, 7, 33])
+def test_gate_folds_every_due_window_in_one_launch(n_ready, half_life):
+    """The gate folds every ready tenant's buffered window into its
+    fleet row and gates the folded rows in one launch: each row equals
+    a solo sketch fed the same batches (bit for bit undecayed, 1e-6
+    decayed), rows not due, freed rows and never-used rows keep their
+    bits, the drift vector is the distance over the folded rows, and
+    no tenant pays a dispatch of its own."""
+    import jax.numpy as jnp
+    from repro.kernels.fleet_gate import drift_gate_fleet
+    arb, ready, idle, rng, first = _fold_fleet(n_ready, half_life)
+    f = arb.fleet
+    solos = {}
+    for i, name in enumerate(ready):
+        view = arb.tenants[name].controller.sketch
+        solo = DeviceSizeSketch(half_life=view.half_life,
+                                num_buckets=view.num_buckets,
+                                bucket_width=view.bucket_width,
+                                window=True)
+        solo.observe_many(first[name])
+        solo.flush_window()
+        batches, weights = _fold_batches(rng, i)
+        for b, w in zip(batches, weights):
+            arb.observe(name, b, w)
+            solo.observe_many(b, w)
+        solo.flush_window()
+        solos[name] = solo
+    for name in idle:
+        arb.observe(name, rng.integers(1, 6000, size=FOLD_EVERY // 2))
+    before = np.asarray(f.sketch)
+    refs = [arb.tenants[n].controller.reference for n in ready]
+    dispatches = {n: arb.tenants[n].controller.sketch.n_dispatches
+                  for n in arb.tenants}
+    launches, folded = arb.n_gate_launches, arb.n_gate_rows_folded
+    with no_implicit_transfers():
+        arb.tick(1)
+    after = np.asarray(f.sketch)
+    assert arb.n_gate_launches == launches + 1
+    assert arb.n_gate_rows_folded == folded + n_ready
+    assert {n: arb.tenants[n].controller.sketch.n_dispatches
+            for n in arb.tenants} == dispatches
+    rows = [f.row_of[n] for n in ready]
+    for name, row in zip(ready, rows):
+        want = np.asarray(solos[name].weights_device)
+        if half_life == float("inf"):
+            np.testing.assert_array_equal(after[row], want)
+        else:
+            np.testing.assert_allclose(after[row], want, rtol=1e-6)
+    untouched = [r for r in range(f.capacity) if r not in rows]
+    assert len(untouched) >= 3           # idle, freed and never-used rows
+    np.testing.assert_array_equal(after[untouched], before[untouched])
+    for name in idle:                    # their windows stay buffered
+        assert arb.tenants[name].controller.sketch._pending
+    got = np.array([arb.tenants[n].controller.last_drift for n in ready])
+    want = np.asarray(drift_gate_fleet(jnp.stack(refs),
+                                       jnp.asarray(after[rows])))
+    np.testing.assert_array_equal(got, want.astype(np.float64))
+
+
+def test_take_window_folds_device_batches_itself():
+    """A window holding a device-resident batch is folded by the view's
+    own launch, and the gate then folds nothing for that row."""
+    import jax.numpy as jnp
+    f = FleetState(capacity=2)
+    cfg = ControllerConfig(page_size=PAGE, device=True, check_every=50)
+    view = f.sketch_view(f.alloc_row("a"), cfg)
+    view.observe_many(np.arange(1, 40))
+    view.observe_many(jnp.arange(40, 90))
+    assert view.take_window() is None
+    assert view.n_dispatches == 1 and not view._pending
+    assert float(jnp.sum(view.weights_device > 0)) == 89
+    view.observe_many(np.arange(1, 10))
+    sizes, weights, decay_total = view.take_window()
+    np.testing.assert_array_equal(sizes, np.arange(1, 10))
+    assert weights.shape == (9,) and 0 < decay_total < 1
+    assert view.take_window() is None
 
 
 def test_streaming_size_sketch_removed():
